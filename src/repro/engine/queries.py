@@ -71,6 +71,7 @@ from typing import Callable
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import hash_table as _ht
 from repro.core.delta import TOMBSTONE, delta_is_empty, delta_stats
@@ -270,36 +271,45 @@ def _pid_probe(fk: jax.Array, dk: jax.Array):
 
 def _filter_aggregate(spec: QuerySpec, fact_cols, dim_cols, probes):
     """Shared tail of every query program: filter-on-the-fly → mask →
-    measure → segment-sum.  ``probes[dim] = (found, dim_row)``."""
+    measure → segment-sum.  ``probes[dim] = (found, dim_row)``.
+
+    Its ops carry the named scopes ``filter`` (join mask, dimension
+    predicates gathered through the probe's rows, fact filter),
+    ``group_key`` (the group-key gathers) and ``aggregate`` (measure, total,
+    segment sum), so a device trace splits the query's time by stage."""
     fact = Table(fact_cols)
     n_rows = fact.n_rows
-    mask = jnp.ones((n_rows,), bool)
     rows: dict[str, jax.Array] = {}
-    for dim in spec.joined_dims():
-        found, r = probes[dim]
-        rows[dim] = r
-        mask = mask & found
-        if dim in spec.dim_filters:
-            dmask = spec.dim_filters[dim](Table(dim_cols[dim]))
-            # filter-on-the-fly while streaming results (paper §4.1.5)
-            mask = mask & dmask[jnp.clip(r, 0, dmask.shape[0] - 1)]
-    if spec.fact_filter is not None:
-        mask = mask & spec.fact_filter(fact)
-    measure = spec.measure(fact)
-    total = jnp.sum(jnp.where(mask, measure.astype(jnp.int32), 0))
+    with jax.named_scope("filter"):
+        mask = jnp.ones((n_rows,), bool)
+        for dim in spec.joined_dims():
+            found, r = probes[dim]
+            rows[dim] = r
+            mask = mask & found
+            if dim in spec.dim_filters:
+                dmask = spec.dim_filters[dim](Table(dim_cols[dim]))
+                # filter-on-the-fly while streaming results (paper §4.1.5)
+                mask = mask & dmask[jnp.clip(r, 0, dmask.shape[0] - 1)]
+        if spec.fact_filter is not None:
+            mask = mask & spec.fact_filter(fact)
+    with jax.named_scope("aggregate"):
+        measure = spec.measure(fact)
+        total = jnp.sum(jnp.where(mask, measure.astype(jnp.int32), 0))
     if not spec.group_by:
         return total, total[None]
     # dense composite group key (small spaces by construction)
-    gk = jnp.zeros((n_rows,), jnp.int32)
-    size = 1
-    for dim, col, card in spec.group_by:
-        c = dim_cols[dim][col]
-        v = c[jnp.clip(rows[dim], 0, c.shape[0] - 1)] % card
-        gk = gk * card + v
-        size *= card
-    groups = jax.ops.segment_sum(
-        jnp.where(mask, measure.astype(jnp.int32), 0),
-        jnp.where(mask, gk, 0), num_segments=size)
+    with jax.named_scope("group_key"):
+        gk = jnp.zeros((n_rows,), jnp.int32)
+        size = 1
+        for dim, col, card in spec.group_by:
+            c = dim_cols[dim][col]
+            v = c[jnp.clip(rows[dim], 0, c.shape[0] - 1)] % card
+            gk = gk * card + v
+            size *= card
+    with jax.named_scope("aggregate"):
+        groups = jax.ops.segment_sum(
+            jnp.where(mask, measure.astype(jnp.int32), 0),
+            jnp.where(mask, gk, 0), num_segments=size)
     return total, groups
 
 
@@ -407,6 +417,13 @@ class _QueryRunner:
         raise NotImplementedError
 
     # -- join primitive: (found, dim_row) per fact row ---------------------
+    def _probe(self, dim: str) -> tuple[jax.Array, jax.Array]:
+        """``_join`` under an ``engine.probe`` host span; ``probe_dim``'s
+        misses, on the engine and on its snapshots, all come through
+        here."""
+        with TraceAnnotation("engine.probe", dim=dim):
+            return self._join(dim)
+
     def _join(self, dim: str) -> tuple[jax.Array, jax.Array]:
         fact = self.tables["lineorder"]
         fk = fact[FACT_FK[dim]]
@@ -452,25 +469,27 @@ class _QueryRunner:
 
         def program(fact_cols, dim_cols, indexes, hots):
             probes: dict[str, tuple[jax.Array, jax.Array]] = {}
-            for dim in spec.joined_dims():
-                fk = fact_cols[FACT_FK[dim]]
-                if mode == "jspim":
-                    if fuse_filter and dim in spec.dim_filters:
-                        dmask = spec.dim_filters[dim](Table(dim_cols[dim]))
-                        pr = lookup_filtered(indexes[dim], fk, dmask,
-                                             impl=impl)
+            with jax.named_scope("probe"):
+                for dim in spec.joined_dims():
+                    fk = fact_cols[FACT_FK[dim]]
+                    if mode == "jspim":
+                        if fuse_filter and dim in spec.dim_filters:
+                            dmask = spec.dim_filters[dim](
+                                Table(dim_cols[dim]))
+                            pr = lookup_filtered(indexes[dim], fk, dmask,
+                                                 impl=impl)
+                        else:
+                            pr = lookup(indexes[dim], fk, impl=impl,
+                                        plan=plans.get(dim),
+                                        hot_codes=hots.get(dim))
+                        probes[dim] = (pr.found,
+                                       jnp.where(pr.found, pr.payload, -1))
+                    elif mode == "baseline":
+                        probes[dim] = baselines.sort_merge_join_unique(
+                            fk, dim_cols[dim][DIM_PK[dim]])
                     else:
-                        pr = lookup(indexes[dim], fk, impl=impl,
-                                    plan=plans.get(dim),
-                                    hot_codes=hots.get(dim))
-                    probes[dim] = (pr.found,
-                                   jnp.where(pr.found, pr.payload, -1))
-                elif mode == "baseline":
-                    probes[dim] = baselines.sort_merge_join_unique(
-                        fk, dim_cols[dim][DIM_PK[dim]])
-                else:
-                    probes[dim] = baselines.partitioned_hash_join_unique(
-                        fk, dim_cols[dim][DIM_PK[dim]])
+                        probes[dim] = baselines.partitioned_hash_join_unique(
+                            fk, dim_cols[dim][DIM_PK[dim]])
             return _filter_aggregate(spec, fact_cols, dim_cols, probes)
 
         prog = jax.jit(program)
@@ -779,6 +798,8 @@ class SSBEngine(_QueryRunner):
         self._hits = 0
         self._misses = 0
         self._invalidations = 0
+        self._probes_run = 0
+        self._probes_mu = threading.Lock()
         self._ingest_batches = 0
         self._compactions = 0
         # compiled per-query programs, keyed by query name
@@ -859,9 +880,10 @@ class SSBEngine(_QueryRunner):
                 return hit
             self.invalidate_probe_cache(dim)  # stale epoch: defensive drop
         self._misses += 1
-        out = self._join(dim)
+        out = self._probe(dim)
         # never capture tracers (engine used under an outer jit trace)
         if not isinstance(out[0], jax.core.Tracer):
+            self._count_probe()
             self._probe_cache[dim] = out
             self._probe_epoch[dim] = self._fact_epoch
             # fresh probe output: a new buffer generation (no snapshot
@@ -888,9 +910,17 @@ class SSBEngine(_QueryRunner):
             del self._probe_cache[dim]
             self._cache_owned.discard(dim)
 
+    def _count_probe(self) -> None:
+        with self._probes_mu:   # snapshots probe from serving threads
+            self._probes_run += 1
+
     def cache_info(self) -> dict:
+        """Probe-cache counters.  ``probes_run`` counts every probe run
+        (``_join``) by the engine *and by its snapshots*, whose misses
+        probe into their own memo and never touch ``hits`` / ``misses``."""
         return {"hits": self._hits, "misses": self._misses,
                 "invalidations": self._invalidations,
+                "probes_run": self._probes_run,
                 "cached_dims": sorted(self._probe_cache),
                 "fact_epoch": self._fact_epoch}
 

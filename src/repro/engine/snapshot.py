@@ -163,14 +163,16 @@ class EpochSnapshot(_QueryRunner):
         Entries frozen from the engine are served as-is; a dimension the
         engine had not cached at freeze time is probed lazily against the
         snapshot's own (immutable) index image and memoized locally —
-        the engine's cache is never touched.
+        the engine's cache is never touched, but the probe counts in its
+        ``cache_info()["probes_run"]``.
         """
         self._check_live()
         hit = self._probe_cache.get(dim)
         if hit is not None:
             return hit
-        out = self._join(dim)
+        out = self._probe(dim)
         if not isinstance(out[0], jax.core.Tracer):
+            self.engine._count_probe()
             self._probe_cache[dim] = out
         return out
 

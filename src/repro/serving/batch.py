@@ -22,12 +22,18 @@ directly, never re-entering the poisoned one-launch program.
 All flavors read only :class:`~repro.engine.queries._QueryRunner` surface
 (``probe_dim`` / ``tables`` / ``indexes``), so a :class:`~repro.engine.
 snapshot.EpochSnapshot` serves batches exactly like the head engine would.
+
+Each program is named ``serve_<flavor>_<query>`` (``serve_batch_Q2_1``), so
+a profiler trace names its device time by flavor and query.  Each launch
+runs under a ``serve.run`` host span (on the batched flavors from operand
+gathering on) and each wait for its answers under ``serve.fetch``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.policy import ExecutionPolicy
 from repro.durability.faults import NULL_FAULTS
@@ -42,6 +48,13 @@ def _bucket(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _named(program, flavor: str, name: str):
+    """Jit ``program`` as ``serve_<flavor>_<query>``: the compiled module
+    is then ``jit_serve_<flavor>_<query>`` in HLO and in a trace."""
+    program.__name__ = f"serve_{flavor}_{name.replace('.', '_')}"
+    return jax.jit(program)
 
 
 class BatchRunner:
@@ -73,7 +86,7 @@ class BatchRunner:
                                              dim_cols, probes)
                 return jax.vmap(one)(params)
 
-            prog = jax.jit(program)
+            prog = _named(program, "batch", name)
             self._batch_programs[name] = prog
         return prog
 
@@ -90,17 +103,18 @@ class BatchRunner:
 
             def program(fact_cols, dim_cols, indexes, params):
                 probes = {}
-                for dim in spec.joined_dims():
-                    pr = lookup(indexes[dim], fact_cols[FACT_FK[dim]])
-                    probes[dim] = (pr.found,
-                                   jnp.where(pr.found, pr.payload, -1))
+                with jax.named_scope("probe"):
+                    for dim in spec.joined_dims():
+                        pr = lookup(indexes[dim], fact_cols[FACT_FK[dim]])
+                        probes[dim] = (pr.found,
+                                       jnp.where(pr.found, pr.payload, -1))
 
                 def one(p):
                     return _filter_aggregate(pq.bind(p), fact_cols,
                                              dim_cols, probes)
                 return jax.vmap(one)(params)
 
-            prog = jax.jit(program)
+            prog = _named(program, "mega", name)
             self._mega_programs[name] = prog
         return prog
 
@@ -113,7 +127,7 @@ class BatchRunner:
                 return _filter_aggregate(pq.bind(p), fact_cols,
                                          dim_cols, probes)
 
-            prog = jax.jit(program)
+            prog = _named(program, "composed", name)
             self._single_programs[name] = prog
         return prog
 
@@ -168,33 +182,38 @@ class BatchRunner:
                     f"got {len(p)}: {tuple(p)!r}")
         flavor = self._resolve_flavor(runner, flavor, composed)
         if flavor == "composed":
-            fact_cols, dim_cols, probes = self._operands(runner, name)
+            operands = self._operands(runner, name)
             prog = self._single_program(name)
             out = []
             for p in params_list:
                 faults.hit(f"kernel_composed:{name}")
-                total, groups = prog(fact_cols, dim_cols, probes,
-                                     jnp.asarray(p, jnp.int32))
-                out.append((int(total), np.asarray(groups)))
+                with TraceAnnotation("serve.run", flavor=flavor, width=1):
+                    total, groups = prog(*operands,
+                                         jnp.asarray(p, jnp.int32))
+                with TraceAnnotation("serve.fetch"):
+                    out.append((int(total), np.asarray(groups)))
             return out
         b = len(params_list)
-        padded = list(params_list) + [params_list[-1]] * (_bucket(b) - b)
-        params = jnp.asarray(np.asarray(padded, np.int32))
-        if flavor == "mega":
-            spec = SSB_QUERIES[name]
-            fact_cols = dict(runner.tables["lineorder"].columns)
-            dim_cols = {d: dict(runner.tables[d].columns)
-                        for d in spec.joined_dims()}
-            idx = {d: effective_index(runner.indexes[d])
-                   for d in spec.joined_dims()}
-            faults.hit(f"kernel_mega:{name}")
-            totals, groups = self._mega_program(name)(
-                fact_cols, dim_cols, idx, params)
-        else:
-            fact_cols, dim_cols, probes = self._operands(runner, name)
-            faults.hit(f"kernel_batch:{name}")
-            totals, groups = self._batch_program(name)(
-                fact_cols, dim_cols, probes, params)
-        totals = np.asarray(totals)
-        groups = np.asarray(groups)
+        width = _bucket(b)
+        with TraceAnnotation("serve.run", flavor=flavor, width=width):
+            padded = list(params_list) + [params_list[-1]] * (width - b)
+            params = jnp.asarray(np.asarray(padded, np.int32))
+            if flavor == "mega":
+                spec = SSB_QUERIES[name]
+                fact_cols = dict(runner.tables["lineorder"].columns)
+                dim_cols = {d: dict(runner.tables[d].columns)
+                            for d in spec.joined_dims()}
+                idx = {d: effective_index(runner.indexes[d])
+                       for d in spec.joined_dims()}
+                faults.hit(f"kernel_mega:{name}")
+                totals, groups = self._mega_program(name)(
+                    fact_cols, dim_cols, idx, params)
+            else:
+                fact_cols, dim_cols, probes = self._operands(runner, name)
+                faults.hit(f"kernel_batch:{name}")
+                totals, groups = self._batch_program(name)(
+                    fact_cols, dim_cols, probes, params)
+        with TraceAnnotation("serve.fetch"):
+            totals = np.asarray(totals)
+            groups = np.asarray(groups)
         return [(int(totals[i]), groups[i]) for i in range(b)]

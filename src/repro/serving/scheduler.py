@@ -26,6 +26,13 @@ The invariant all four gates preserve: **degraded or rejected, never
 wrong** — every ``ok`` response is bit-identical to the single-threaded
 oracle at the epoch the response reports (chaos-tested in
 ``tests/test_serving_chaos.py``).
+
+Tracing: each batch runs under a ``serve.batch`` host span (arguments
+``query``, ``n``, ``batch`` — the id its tickets carry — and ``wait_us``,
+its requests' queue waits), with children ``serve.refresh``,
+``serve.checkout``, ``serve.run``, ``serve.fetch`` and ``serve.backoff``.
+The spans are ``jax.profiler.TraceAnnotation``: they cost about a
+microsecond each unless a profiler trace is running.
 """
 from __future__ import annotations
 
@@ -35,6 +42,7 @@ import time
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import costmodel
 from repro.core.planner import plan_batch
@@ -101,7 +109,9 @@ class Ticket:
         self._ev = threading.Event()
         self.response: Response | None = None
         self.submitted_at: float | None = None   # wall (time.monotonic)
+        self.dequeued_at: float | None = None    # left the queue
         self.resolved_at: float | None = None
+        self.batch_id: int | None = None         # ``serve.batch`` span id
 
     def _resolve(self, resp: Response) -> None:
         self.resolved_at = time.monotonic()
@@ -205,11 +215,15 @@ class QueryScheduler:
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._closed = False
+        self._batch_seq = 0
+        # ``queue_wait_s`` sums ``dequeued_at - submitted_at`` over the
+        # ``dequeued`` requests: the mean queue wait without a trace
         self.stats = {"submitted": 0, "completed": 0, "rejected": 0,
                       "timed_out": 0, "failed": 0, "retries": 0,
                       "batches": 0, "composed_batches": 0,
                       "refresh_failures": 0, "bg_compactions": 0,
-                      "bg_compact_conflicts": 0, "maintained_served": 0}
+                      "bg_compact_conflicts": 0, "maintained_served": 0,
+                      "dequeued": 0, "queue_wait_s": 0.0}
 
     # -- admission ---------------------------------------------------------
     def submit(self, name: str, params=None, *,
@@ -272,7 +286,8 @@ class QueryScheduler:
                 return
             try:
                 self.faults.hit("snapshot_refresh")
-                snap = self.engine.snapshot()
+                with TraceAnnotation("serve.refresh"):
+                    snap = self.engine.snapshot()
             except Exception:
                 self.stats["refresh_failures"] += 1
                 return
@@ -321,6 +336,11 @@ class QueryScheduler:
                 n_rows=self._pin.snap.tables["lineorder"].n_rows,
                 max_batch=cfg.max_batch)
             take = same[:plan.size]
+            wall = time.monotonic()
+            for it in take:
+                it.ticket.dequeued_at = wall
+                self.stats["queue_wait_s"] += wall - it.ticket.submitted_at
+            self.stats["dequeued"] += len(take)
             taken = set(map(id, take))
             self._queue = [it for it in self._queue
                            if id(it) not in taken]
@@ -371,8 +391,21 @@ class QueryScheduler:
 
     # -- execution ---------------------------------------------------------
     def _execute(self, batch: list[_Item]) -> None:
-        cfg = self.config
         name = batch[0].name
+        with self._mu:
+            self._batch_seq += 1
+            bid = self._batch_seq
+        for it in batch:
+            it.ticket.batch_id = bid
+        waits = ";".join(str(round(1e6 * (it.ticket.dequeued_at
+                                          - it.ticket.submitted_at)))
+                         for it in batch)
+        with TraceAnnotation("serve.batch", query=name, n=len(batch),
+                             batch=bid, wait_us=waits):
+            self._execute_traced(batch, name)
+
+    def _execute_traced(self, batch: list[_Item], name: str) -> None:
+        cfg = self.config
         self._refresh()
         now = cfg.clock()
         live = []
@@ -400,7 +433,8 @@ class QueryScheduler:
         while True:
             with self._mu:
                 pin = self._pin.acquire()
-            worker = self.pool.checkout(cfg.checkout_timeout_s)
+            with TraceAnnotation("serve.checkout"):
+                worker = self.pool.checkout(cfg.checkout_timeout_s)
             err: Exception | None = None
             results = None
             if worker is None:
@@ -449,7 +483,8 @@ class QueryScheduler:
                         reason=f"batch failed after {attempt} attempts: "
                                f"{err}"))
                 return
-            time.sleep(attempt * cfg.backoff_s)
+            with TraceAnnotation("serve.backoff"):
+                time.sleep(attempt * cfg.backoff_s)
             self._refresh(force=True)   # retry against a fresh snapshot
 
     # -- drive -------------------------------------------------------------

@@ -6,9 +6,11 @@ overflow, primitives Mosaic cannot lower).  Shapes are real widths:
 m = 2**20 probes, W = 128 lanes, and the planner's group-space cap for the
 one-launch kernel.  Interpret-mode parity lives in test_kernels.py and
 test_fused_query.py; this file checks only that the compiled lowering
-exists.
+exists, and which ops of the serving programs the compiler leaves
+without an ``op_name``.
 """
 import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.planner import MAX_MEGA_SEGMENTS
+from repro.engine.queries import SSB_QUERIES
 from repro.kernels.ops import KERNEL_REGISTRY
+from repro.serving import PARAM_QUERIES, BatchRunner
 
 M, W, BUCKETS, DELTA_W = 1 << 20, 128, 1 << 16, 8
 
@@ -103,3 +107,88 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     compiled = jax.jit(
         lambda *a: op.fn(*a, **kw, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- serving programs: which ops the compiled HLO leaves unnamed ----------
+SF1_ROWS = {"lineorder": 6_000_000, "customer": 30_000, "supplier": 2_000,
+            "part": 200_000, "date": 2_556}
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"(?<![\w])(probe|filter|group_key|aggregate)(?![\w])")
+
+
+def _computations(text):
+    """Computation name -> its body lines."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None and line.strip() and line != "}":
+            comps[name].append(line)
+    return comps
+
+
+def _named_scopes(comps, comp, seen=None):
+    """Scopes of every named op inside ``comp`` and the computations it
+    calls."""
+    seen = set() if seen is None else seen
+    out = set()
+    for line in comps.get(comp, []):
+        m = _OP_NAME.search(line)
+        if m:
+            out |= set(_SCOPE.findall(m.group(1))[:1]) or {"other"}
+        callee = re.search(r"calls=(%[\w.\-]+)", line)
+        if callee and callee.group(1) not in seen:
+            seen.add(callee.group(1))
+            out |= _named_scopes(comps, callee.group(1), seen)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(SSB_QUERIES))
+def test_serving_programs_unnamed_custom_fusions_are_aggregate(
+        name, one_chip, no_persistent_cache, serving_operands):
+    """The benchmark's device-scope reader counts a serving program's
+    custom fusion that carries no ``op_name`` as the group-by's
+    scatter-add: at SF 1 shapes every such fusion holds ``aggregate`` ops
+    only, and every named op at the entry has one of the four scopes."""
+    runner = BatchRunner()
+    fact, dims, probes = serving_operands(name)
+    params = jax.ShapeDtypeStruct((2, PARAM_QUERIES[name].n_params),
+                                  jnp.int32, sharding=one_chip)
+    text = runner._batch_program(name).lower(
+        fact, dims, probes, params).compile().as_text()
+    comps = _computations(text)
+    entry = next(c for c in comps if text.find(f"ENTRY {c} ") >= 0)
+    unnamed = 0
+    for line in comps[entry]:
+        if "kind=kCustom" in line and not _OP_NAME.search(line):
+            unnamed += 1
+            callee = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+            assert _named_scopes(comps, callee) == {"aggregate"}, line[:120]
+    if SSB_QUERIES[name].group_by:
+        assert unnamed >= 1        # the scatter-add lost its op_name
+    assert f"HloModule jit_serve_batch_{name.replace('.', '_')}" in text
+
+
+@pytest.fixture(scope="module")
+def serving_operands(one_chip):
+    """A serving batch program's operands at SF 1 row counts, as shapes
+    on the described chip (column names from a tiny engine)."""
+    from repro.engine import SSBEngine, generate_ssb
+
+    eng = SSBEngine(generate_ssb(sf=0.002, seed=3), mode="jspim")
+    eng.warm_cache()
+
+    def sds(x, n):
+        return jax.ShapeDtypeStruct((n,), x.dtype, sharding=one_chip)
+
+    def make(name):
+        fact, dims, probes = BatchRunner._operands(eng, name)
+        n = SF1_ROWS["lineorder"]
+        return ({k: sds(v, n) for k, v in fact.items()},
+                {d: {k: sds(v, SF1_ROWS[d]) for k, v in c.items()}
+                 for d, c in dims.items()},
+                {d: (sds(f, n), sds(r, n)) for d, (f, r) in probes.items()})
+
+    return make
