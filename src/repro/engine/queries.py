@@ -18,6 +18,15 @@ Execution pipeline (DESIGN.md §4):
     is reused by every query that touches the dimension.  The §3.2.3 update
     commands (``entry_update`` / ``index_update`` / ``table_update``) go
     through the engine and invalidate the affected dimension's cache entry.
+  * **Fact-aligned column cache** — the dimension columns the templates
+    read (``ATTR_COLUMNS``: 12 int32 columns) are gathered once through
+    each cached ``dim_row`` (``dim_attrs``), so the query programs compare
+    and combine fact-length columns elementwise instead of gathering a
+    dimension-sized mask or group column through the rows per query.  An
+    entry is keyed on the probe entry it was gathered from and on the
+    dimension ``Table`` it read, lives and dies with that probe entry
+    (invalidation drops it, a fact append splices the gathered tail in,
+    snapshots freeze it), and costs 4 B per fact row per column.
   * **Fused per-query programs** — each ``QuerySpec`` compiles (once) into a
     single jitted filter→mask→measure→segment-sum program consuming the
     cached probes, so a warm query is one XLA dispatch.  A second "full"
@@ -245,6 +254,16 @@ _q("Q4.3", {"customer": _eq("region", 1), "supplier": _eq("nation", 6),
    None, _profit, [("date", "year", 7), ("supplier", "city", 250),
                    ("part", "brand", 1000)])
 
+# The dimension columns the templates read, in their predicates (the
+# parameterized variants of ``serving/params.py`` included) and group
+# keys: the fact-aligned column cache gathers exactly these per dimension.
+ATTR_COLUMNS: dict[str, tuple[str, ...]] = {
+    "customer": ("region", "nation", "city"),
+    "supplier": ("region", "nation", "city"),
+    "part": ("mfgr", "category", "brand"),
+    "date": ("year", "yearmonthnum", "weeknuminyear"),
+}
+
 
 # ---------------------------------------------------------------------------
 # jitted probe primitives (shared across engines; cached by jax by shapes)
@@ -269,14 +288,52 @@ def _pid_probe(fk: jax.Array, dk: jax.Array):
     return baselines.partitioned_hash_join_unique(fk, dk)
 
 
-def _filter_aggregate(spec: QuerySpec, fact_cols, dim_cols, probes):
+@jax.jit
+def _gather_attrs(cols: dict, row: jax.Array) -> dict:
+    """Each dimension column gathered through a probe's ``dim_row``: the
+    fact-aligned columns.  A miss (``dim_row`` -1) reads row 0's value,
+    as the query programs' clipped gather does, and ``found`` masks it."""
+    n = next(iter(cols.values())).shape[0]
+    r = jnp.clip(row, 0, n - 1)
+    return {k: c[r] for k, c in cols.items()}
+
+
+@partial(jax.jit, static_argnames=("n_tail",))
+def _extend_attrs(attrs: dict, cols: dict, row: jax.Array,
+                  start: jax.Array, *, n_tail: int) -> dict:
+    """Fact-aligned columns after a fact append: ``row`` is the extended
+    probe, whose ``n_tail`` rows at ``start`` are gathered and spliced in
+    (a copy, never a donation: snapshots and callers alias the columns).
+    When the fact capacity grew, the new padding reads row 0's value,
+    exactly what a full re-gather of its -1 rows would read."""
+    n = next(iter(cols.values())).shape[0]
+    r = jnp.clip(jax.lax.dynamic_slice(row, (start,), (n_tail,)), 0, n - 1)
+    out = {}
+    for k, a in attrs.items():
+        c = cols[k]
+        if a.shape[0] != row.shape[0]:
+            a = jnp.concatenate(
+                [a, jnp.full((row.shape[0] - a.shape[0],), c[0], a.dtype)])
+        out[k] = jax.lax.dynamic_update_slice(a, c[r], (start,))
+    return out
+
+
+def _filter_aggregate(spec: QuerySpec, fact_cols, dim_cols, probes,
+                      attrs=None):
     """Shared tail of every query program: filter-on-the-fly → mask →
     measure → segment-sum.  ``probes[dim] = (found, dim_row)``.
 
+    With ``attrs`` (``attrs[dim][col]``: the fact-aligned columns of
+    :meth:`_QueryRunner.dim_attrs`) predicates and group keys read those
+    columns elementwise and ``dim_cols`` is not read; without, each
+    predicate runs on the dimension table and its mask, like each group
+    column, is gathered through the probe's rows.  Both give the same
+    bits: the predicates are elementwise and read the same values.
+
     Its ops carry the named scopes ``filter`` (join mask, dimension
-    predicates gathered through the probe's rows, fact filter),
-    ``group_key`` (the group-key gathers) and ``aggregate`` (measure, total,
-    segment sum), so a device trace splits the query's time by stage."""
+    predicates, fact filter), ``group_key`` (the group keys) and
+    ``aggregate`` (measure, total, segment sum), so a device trace splits
+    the query's time by stage."""
     fact = Table(fact_cols)
     n_rows = fact.n_rows
     rows: dict[str, jax.Array] = {}
@@ -286,7 +343,11 @@ def _filter_aggregate(spec: QuerySpec, fact_cols, dim_cols, probes):
             found, r = probes[dim]
             rows[dim] = r
             mask = mask & found
-            if dim in spec.dim_filters:
+            if dim not in spec.dim_filters:
+                continue
+            if attrs is not None:
+                mask = mask & spec.dim_filters[dim](Table(attrs[dim]))
+            else:
                 dmask = spec.dim_filters[dim](Table(dim_cols[dim]))
                 # filter-on-the-fly while streaming results (paper §4.1.5)
                 mask = mask & dmask[jnp.clip(r, 0, dmask.shape[0] - 1)]
@@ -302,8 +363,11 @@ def _filter_aggregate(spec: QuerySpec, fact_cols, dim_cols, probes):
         gk = jnp.zeros((n_rows,), jnp.int32)
         size = 1
         for dim, col, card in spec.group_by:
-            c = dim_cols[dim][col]
-            v = c[jnp.clip(rows[dim], 0, c.shape[0] - 1)] % card
+            if attrs is not None:
+                v = attrs[dim][col] % card
+            else:
+                c = dim_cols[dim][col]
+                v = c[jnp.clip(rows[dim], 0, c.shape[0] - 1)] % card
             gk = gk * card + v
             size *= card
     with jax.named_scope("aggregate"):
@@ -378,8 +442,9 @@ class _QueryRunner:
     """Shared query-execution surface of the live engine and its snapshots.
 
     Subclasses provide the state (``tables`` / ``indexes`` / ``plans`` /
-    ``_hot_codes`` / ``mode`` / ``probe_impl`` plus the two program
-    caches) and a ``probe_dim`` implementation; everything from the join
+    ``_hot_codes`` / ``mode`` / ``probe_impl``, the probe and column
+    caches, the program caches) and ``probe_dim`` / ``_count_attrs``
+    implementations; everything from the join
     primitive to ``run_all`` lives here, identical between the mutable
     ``SSBEngine`` and a frozen ``EpochSnapshot``.  That sharing is the
     MVCC serving contract (DESIGN.md §9): a snapshot answers queries
@@ -394,6 +459,9 @@ class _QueryRunner:
     indexes: dict[str, DimIndex]
     plans: dict[str, SchedulePlan]
     _hot_codes: dict[str, jax.Array]
+    _probe_cache: dict[str, tuple[jax.Array, jax.Array]]
+    # dim -> (probe entry, dimension Table, {col: fact-aligned column})
+    _attr_cache: dict[str, tuple[tuple, Table, dict]]
     _cached_programs: dict[str, Callable]
     _full_programs: dict[str, Callable]
     _suite_programs: dict[tuple, Callable]
@@ -415,6 +483,41 @@ class _QueryRunner:
 
     def probe_dim(self, dim: str) -> tuple[jax.Array, jax.Array]:
         raise NotImplementedError
+
+    def _count_attrs(self, gathers: int = 0, hits: int = 0) -> None:
+        raise NotImplementedError
+
+    def dim_attrs(self, dim: str, probe=None) -> dict[str, jax.Array]:
+        """``ATTR_COLUMNS[dim]`` gathered through the cached ``dim_row``:
+        one fact-aligned int32 column per name, gathered once per probe
+        cache entry and reused by every query.
+
+        ``probe`` is the ``probe_dim(dim)`` result the caller already
+        holds (read here when omitted).  An entry serves only the probe
+        entry and dimension ``Table`` it was gathered from; a probe that
+        is not the cached entry (or a traced one) is gathered and not
+        kept.  Gathers and hits count in the engine's ``cache_info()``,
+        a snapshot's included."""
+        if probe is None:
+            probe = self.probe_dim(dim)
+        table = self.tables[dim]
+        hit = self._attr_cache.get(dim)
+        if hit is not None and hit[0] is probe and hit[1] is table:
+            self._count_attrs(hits=1)
+            return hit[2]
+        cols = ATTR_COLUMNS[dim]
+        with TraceAnnotation("engine.attr_gather", dim=dim):
+            attrs = _gather_attrs({c: table[c] for c in cols}, probe[1])
+        if not isinstance(probe[1], jax.core.Tracer):
+            self._count_attrs(gathers=len(cols))
+            if probe is self._probe_cache.get(dim):
+                self._attr_cache[dim] = (probe, table, attrs)
+        return attrs
+
+    def _cached_operands(self, dims) -> tuple[dict, dict]:
+        """Cached probes and fact-aligned columns of ``dims``."""
+        probes = {d: self.probe_dim(d) for d in dims}
+        return probes, {d: self.dim_attrs(d, probes[d]) for d in dims}
 
     # -- join primitive: (found, dim_row) per fact row ---------------------
     def _probe(self, dim: str) -> tuple[jax.Array, jax.Array]:
@@ -444,11 +547,17 @@ class _QueryRunner:
 
     # -- compiled query programs ------------------------------------------
     def _cached_program(self, name: str) -> Callable:
-        """Jitted filter→mask→aggregate consuming cached probes."""
+        """Jitted filter→mask→aggregate consuming cached probes and
+        fact-aligned columns."""
         prog = self._cached_programs.get(name)
         if prog is None:
             spec = SSB_QUERIES[name]
-            prog = jax.jit(partial(_filter_aggregate, spec))
+
+            def program(fact_cols, probes, attrs):
+                return _filter_aggregate(spec, fact_cols, None, probes,
+                                         attrs)
+
+            prog = jax.jit(program)
             self._cached_programs[name] = prog
         return prog
 
@@ -498,11 +607,11 @@ class _QueryRunner:
 
     def _suite_program(self, names: tuple[str, ...]) -> Callable:
         """ONE jitted program executing every named query's filter→mask→
-        aggregate tail against the shared cached probes — a single
-        dispatch replaces ``len(names)``, and the compiler shares the
-        subexpressions the flights repeat (identical group-key
-        construction across Q2.x / Q3.2–3.4, the revenue and profit
-        measures).  On CPU the per-dispatch overhead this removes is
+        aggregate tail against the shared cached probes and fact-aligned
+        columns — a single dispatch replaces ``len(names)``, and the
+        compiler shares the subexpressions the flights repeat (identical
+        group-key construction across Q2.x / Q3.2–3.4, the revenue and
+        profit measures).  On CPU the per-dispatch overhead this removes is
         small next to the per-query tails; the measured mega win lives in
         :meth:`_mega_suite_program`, which also folds the *probes* in.
         """
@@ -510,9 +619,9 @@ class _QueryRunner:
         if prog is None:
             specs = [SSB_QUERIES[n] for n in names]
 
-            def program(fact_cols, dim_cols, probes):
-                return {s.name: _filter_aggregate(s, fact_cols, dim_cols,
-                                                  probes)
+            def program(fact_cols, probes, attrs):
+                return {s.name: _filter_aggregate(s, fact_cols, None,
+                                                  probes, attrs)
                         for s in specs}
 
             prog = jax.jit(program)
@@ -606,14 +715,14 @@ class _QueryRunner:
         use_cache = self.policy.use_cache if use_cache is None else use_cache
         fusion = self.policy.fusion if fusion is None else fusion
         fact_cols = dict(self.tables["lineorder"].columns)
+        if use_cache and not (fusion == "mega" and self.mode == "jspim"):
+            probes, attrs = self._cached_operands(spec.joined_dims())
+            return self._cached_program(name)(fact_cols, probes, attrs)
         dim_cols = self._dim_cols(spec)
         if fusion == "mega" and self.mode == "jspim":
             idx = {d: effective_index(self.indexes[d])
                    for d in spec.joined_dims()}
             return self._mega_program(name)(fact_cols, dim_cols, idx)
-        if use_cache:
-            probes = {d: self.probe_dim(d) for d in spec.joined_dims()}
-            return self._cached_program(name)(fact_cols, dim_cols, probes)
         if self.mode == "jspim":
             idx = {d: effective_index(self.indexes[d])
                    for d in spec.joined_dims()}
@@ -655,11 +764,11 @@ class _QueryRunner:
             dims = sorted({d for n in names
                            for d in SSB_QUERIES[n].joined_dims()})
             fact_cols = dict(self.tables["lineorder"].columns)
-            dim_cols = {d: dict(self.tables[d].columns) for d in dims}
             if use_cache:
-                probes = {d: self.probe_dim(d) for d in dims}
+                probes, attrs = self._cached_operands(dims)
                 return self._suite_program(tuple(names))(
-                    fact_cols, dim_cols, probes)
+                    fact_cols, probes, attrs)
+            dim_cols = {d: dict(self.tables[d].columns) for d in dims}
             idx = {d: effective_index(self.indexes[d]) for d in dims} \
                 if self.mode == "jspim" else {}
             hots = {d: self._hot_codes[d] for d in dims
@@ -766,6 +875,9 @@ class SSBEngine(_QueryRunner):
         # each entry stamped with the fact epoch it is consistent with
         self._probe_cache: dict[str, tuple[jax.Array, jax.Array]] = {}
         self._probe_epoch: dict[str, int] = {}
+        # fact-aligned column cache (``dim_attrs``): per dim, the probe
+        # entry and dimension Table it was gathered from, and the columns
+        self._attr_cache: dict[str, tuple[tuple, Table, dict]] = {}
         # dims whose cached arrays were (re)built by the extension path —
         # nothing external can alias those, so the next tail splice may
         # donate them and update in place (O(tail) instead of O(stream))
@@ -799,6 +911,8 @@ class SSBEngine(_QueryRunner):
         self._misses = 0
         self._invalidations = 0
         self._probes_run = 0
+        self._attr_gathers = 0
+        self._attr_hits = 0
         self._probes_mu = threading.Lock()
         self._ingest_batches = 0
         self._compactions = 0
@@ -895,32 +1009,60 @@ class SSBEngine(_QueryRunner):
         return out
 
     def warm_cache(self, dims=None) -> None:
-        """Probe every (or the given) dimension into the cache up front."""
+        """Probe every (or the given) dimension into the cache up front,
+        and gather its fact-aligned columns."""
         for dim in (dims if dims is not None else DIM_PK):
-            self.probe_dim(dim)
+            self.dim_attrs(dim)
 
     def invalidate_probe_cache(self, dim: str | None = None) -> None:
-        """Drop cached probes — all dims, or one (after an index update)."""
+        """Drop cached probes — all dims, or one (after an index update) —
+        with the fact-aligned columns gathered from them."""
         if dim is None:
             self._invalidations += len(self._probe_cache)
             self._probe_cache.clear()
             self._cache_owned.clear()
+            self._attr_cache.clear()
         elif dim in self._probe_cache:
             self._invalidations += 1
             del self._probe_cache[dim]
             self._cache_owned.discard(dim)
+            self._attr_cache.pop(dim, None)
 
     def _count_probe(self) -> None:
         with self._probes_mu:   # snapshots probe from serving threads
             self._probes_run += 1
 
+    def _count_attrs(self, gathers: int = 0, hits: int = 0) -> None:
+        with self._probes_mu:
+            self._attr_gathers += gathers
+            self._attr_hits += hits
+
+    def _extend_attr_cache(self, dim: str, start: jax.Array,
+                           n_tail: int) -> None:
+        """Carry ``dim``'s fact-aligned columns over a fact append whose
+        probe extension just replaced the cache entry: gather the tail
+        only and splice it in (a copy of each column)."""
+        entry = self._attr_cache.pop(dim, None)
+        table = self.tables[dim]
+        if entry is None or entry[1] is not table:
+            return
+        probe = self._probe_cache[dim]
+        cols = {c: table[c] for c in entry[2]}
+        self._attr_cache[dim] = (probe, table, _extend_attrs(
+            entry[2], cols, probe[1], start, n_tail=n_tail))
+
     def cache_info(self) -> dict:
         """Probe-cache counters.  ``probes_run`` counts every probe run
         (``_join``) by the engine *and by its snapshots*, whose misses
-        probe into their own memo and never touch ``hits`` / ``misses``."""
+        probe into their own memo and never touch ``hits`` / ``misses``.
+        ``attr_gathers`` counts fact-aligned columns gathered in full
+        (``dim_attrs`` misses), ``attr_hits`` the ``dim_attrs`` reads
+        served from a cache, engine and snapshots alike."""
         return {"hits": self._hits, "misses": self._misses,
                 "invalidations": self._invalidations,
                 "probes_run": self._probes_run,
+                "attr_gathers": self._attr_gathers,
+                "attr_hits": self._attr_hits,
                 "cached_dims": sorted(self._probe_cache),
                 "fact_epoch": self._fact_epoch}
 
@@ -1430,6 +1572,7 @@ class SSBEngine(_QueryRunner):
                 start,
                 self._hot_codes.get(dim), impl=self.probe_impl,
                 plan=self.plans.get(dim))
+            self._extend_attr_cache(dim, start, bp)
             self._probe_epoch[dim] = self._fact_epoch
             self._cache_owned.add(dim)
             if fresh:

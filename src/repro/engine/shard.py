@@ -386,6 +386,9 @@ class ShardedSSBEngine(SSBEngine):
             self._probe_cache[dim] = extend(
                 effective_index(self.indexes[dim]), None, found, row,
                 tails[FACT_FK[dim]], start_t)
+            # the fact-aligned columns are gathered again, lazily, from
+            # the extended probe (no per-shard splice program)
+            self._attr_cache.pop(dim, None)
             self._probe_epoch[dim] = self._fact_epoch
             self._cache_owned.add(dim)
             if fresh:

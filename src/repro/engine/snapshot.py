@@ -4,11 +4,12 @@ JSPIM's rank-level design assumes queries stream against a stable index
 image while updates land elsewhere (paper §3.2.3); this module is the
 write-side half of that story (DESIGN.md §9).  ``SSBEngine.snapshot()``
 freezes one consistent image — dimension tables, dictionaries, hash
-tables, delta buffers, fact table, probe cache and plan set, all at the
-engine's current epoch — as an :class:`EpochSnapshot`.  The engine then
-keeps advancing its private head image (``append_fact_rows`` / ``ingest``
-/ ``compact``) and publishes every step with an atomic epoch bump, while
-the snapshot keeps serving queries at its epoch:
+tables, delta buffers, fact table, probe cache, fact-aligned column cache
+and plan set, all at the engine's current epoch — as an
+:class:`EpochSnapshot`.  The engine then keeps advancing its private head
+image (``append_fact_rows`` / ``ingest`` / ``compact``) and publishes every
+step with an atomic epoch bump, while the snapshot keeps serving queries
+at its epoch:
 
 * **Zero-copy freeze** — jax arrays are immutable values, so the snapshot
   simply aliases the engine's buffers.  The only mutation in the system
@@ -70,6 +71,11 @@ class EpochSnapshot(_QueryRunner):
         self._probe_cache = {
             d: e for d, e in engine._probe_cache.items()
             if engine._probe_epoch.get(d) == engine._fact_epoch}
+        # fact-aligned columns frozen with the probe entries they were
+        # gathered from; a miss gathers into this snapshot's own memo
+        self._attr_cache = {
+            d: e for d, e in engine._attr_cache.items()
+            if e[0] is self._probe_cache.get(d) and e[1] is tables[d]}
         # compiled programs: the cached-probe programs are epoch-oblivious
         # (keyed by query + shapes) and shared with the engine outright;
         # the fused full programs close over plans statically, so the
@@ -127,6 +133,7 @@ class EpochSnapshot(_QueryRunner):
         self.plans = {}
         self._hot_codes = {}
         self._probe_cache = {}
+        self._attr_cache = {}
         self._full_programs = {}
         self.maintained = None
         # rebind (not clear!) the shared one-launch program dicts
@@ -176,10 +183,21 @@ class EpochSnapshot(_QueryRunner):
             self._probe_cache[dim] = out
         return out
 
+    def dim_attrs(self, dim: str, probe=None) -> dict[str, jax.Array]:
+        """Fact-aligned columns at this snapshot's epoch: entries frozen
+        from the engine are served as-is, a miss is gathered into the
+        snapshot's own memo (the engine's cache is never touched)."""
+        self._check_live()
+        return super().dim_attrs(dim, probe)
+
+    def _count_attrs(self, gathers: int = 0, hits: int = 0) -> None:
+        self.engine._count_attrs(gathers=gathers, hits=hits)
+
     def warm_cache(self, dims=None) -> None:
-        """Probe every (or the given) dimension into the snapshot cache."""
+        """Probe every (or the given) dimension into the snapshot cache,
+        and gather its fact-aligned columns."""
         for dim in (dims if dims is not None else DIM_PK):
-            self.probe_dim(dim)
+            self.dim_attrs(dim)
 
     def run(self, name: str, *, use_cache: bool | None = None,
             fusion: str | None = None):
@@ -189,6 +207,7 @@ class EpochSnapshot(_QueryRunner):
     def cache_info(self) -> dict:
         return {"epoch": self.epoch, "fact_epoch": self.fact_epoch,
                 "cached_dims": sorted(self._probe_cache),
+                "attr_dims": sorted(self._attr_cache),
                 "released": self._released}
 
 

@@ -1,11 +1,13 @@
 """Batched dispatch: many parameter vectors through one compiled program.
 
 The fused path vmaps the engine's shared ``_filter_aggregate`` tail over a
-``(B, P)`` int32 parameter array — the fact/dim columns and cached probes
-are closed over as non-mapped operands, so a batch of B compatible
-requests costs one dispatch instead of B.  Batch width is bucketed to
-powers of two (replicating the last row) so the number of distinct traces
-per query id is logarithmic in the largest batch ever served.
+``(B, P)`` int32 parameter array — the fact columns, cached probes and
+fact-aligned dimension columns (``dim_attrs``) are closed over as
+non-mapped operands, so a batch of B compatible requests costs one
+dispatch instead of B, and no dimension column is gathered per query.
+Batch width is bucketed to powers of two (replicating the last row) so
+the number of distinct traces per query id is logarithmic in the largest
+batch ever served.
 
 The composed path is the degraded flavor the circuit breaker falls back
 to: one request at a time through a plain (non-vmapped) jit of the same
@@ -20,8 +22,9 @@ mega → composed: a breaker opened by mega faults serves composed
 directly, never re-entering the poisoned one-launch program.
 
 All flavors read only :class:`~repro.engine.queries._QueryRunner` surface
-(``probe_dim`` / ``tables`` / ``indexes``), so a :class:`~repro.engine.
-snapshot.EpochSnapshot` serves batches exactly like the head engine would.
+(``probe_dim`` / ``dim_attrs`` / ``tables`` / ``indexes``), so a
+:class:`~repro.engine.snapshot.EpochSnapshot` serves batches exactly like
+the head engine would.
 
 Each program is named ``serve_<flavor>_<query>`` (``serve_batch_Q2_1``), so
 a profiler trace names its device time by flavor and query.  Each launch
@@ -80,10 +83,10 @@ class BatchRunner:
         if prog is None:
             pq = PARAM_QUERIES[name]
 
-            def program(fact_cols, dim_cols, probes, params):
+            def program(fact_cols, attrs, probes, params):
                 def one(p):
-                    return _filter_aggregate(pq.bind(p), fact_cols,
-                                             dim_cols, probes)
+                    return _filter_aggregate(pq.bind(p), fact_cols, None,
+                                             probes, attrs)
                 return jax.vmap(one)(params)
 
             prog = _named(program, "batch", name)
@@ -123,9 +126,9 @@ class BatchRunner:
         if prog is None:
             pq = PARAM_QUERIES[name]
 
-            def program(fact_cols, dim_cols, probes, p):
-                return _filter_aggregate(pq.bind(p), fact_cols,
-                                         dim_cols, probes)
+            def program(fact_cols, attrs, probes, p):
+                return _filter_aggregate(pq.bind(p), fact_cols, None,
+                                         probes, attrs)
 
             prog = _named(program, "composed", name)
             self._single_programs[name] = prog
@@ -134,12 +137,12 @@ class BatchRunner:
     # -- inputs ------------------------------------------------------------
     @staticmethod
     def _operands(runner, name: str):
-        spec = SSB_QUERIES[name]
+        """Fact columns, fact-aligned dimension columns and cached probes
+        of the batch and composed programs."""
         fact_cols = dict(runner.tables["lineorder"].columns)
-        dim_cols = {d: dict(runner.tables[d].columns)
-                    for d in spec.joined_dims()}
-        probes = {d: runner.probe_dim(d) for d in spec.joined_dims()}
-        return fact_cols, dim_cols, probes
+        probes, attrs = runner._cached_operands(
+            SSB_QUERIES[name].joined_dims())
+        return fact_cols, attrs, probes
 
     # -- execution ---------------------------------------------------------
     def _resolve_flavor(self, runner, flavor: str | None,
@@ -209,10 +212,10 @@ class BatchRunner:
                 totals, groups = self._mega_program(name)(
                     fact_cols, dim_cols, idx, params)
             else:
-                fact_cols, dim_cols, probes = self._operands(runner, name)
+                fact_cols, attrs, probes = self._operands(runner, name)
                 faults.hit(f"kernel_batch:{name}")
                 totals, groups = self._batch_program(name)(
-                    fact_cols, dim_cols, probes, params)
+                    fact_cols, attrs, probes, params)
         with TraceAnnotation("serve.fetch"):
             totals = np.asarray(totals)
             groups = np.asarray(groups)

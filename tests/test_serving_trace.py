@@ -1,6 +1,6 @@
 """The serving path's tracing: queue-wait stamps and counters, host spans
 in a profiler trace, named query programs with device scopes, and the
-engine-wide probe counter."""
+engine-wide probe and column-gather counters."""
 import glob
 
 import jax
@@ -103,6 +103,12 @@ def test_profiler_trace_holds_serving_and_probe_spans(tables, tmp_path):
     assert sorted(p[3]["dim"] for p in probes) == ["date", "part",
                                                    "supplier"]
     assert all(_inside(p, run) for p in probes)
+    # the snapshot gathers each dimension's fact-aligned columns once
+    gathers = by["engine.attr_gather"]
+    assert sorted(g[3]["dim"] for g in gathers) == ["date", "part",
+                                                    "supplier"]
+    assert all(_inside(g, run) for g in gathers)
+    assert eng.cache_info()["attr_gathers"] == 3 + 3 + 3
 
 
 def _compiled_text(prog, *args):
@@ -114,18 +120,19 @@ def test_serving_programs_are_named_and_scoped(tables):
     eng.warm_cache()
     runner = BatchRunner()
     name = "Q2.1"
-    fact, dims, probes = runner._operands(eng, name)
+    fact, attrs, probes = runner._operands(eng, name)
     params = jnp.asarray(np.asarray([PARAM_QUERIES[name].defaults],
                                     np.int32))
-    text = _compiled_text(runner._batch_program(name), fact, dims, probes,
+    text = _compiled_text(runner._batch_program(name), fact, attrs, probes,
                           params)
     assert text.startswith("HloModule jit_serve_batch_Q2_1")
     for scope in ("filter", "group_key", "aggregate"):
         assert f'op_name="jit(serve_batch_Q2_1)/vmap({scope})/' in text
-    text = _compiled_text(runner._single_program(name), fact, dims, probes,
+    text = _compiled_text(runner._single_program(name), fact, attrs, probes,
                           params[0])
     assert text.startswith("HloModule jit_serve_composed_Q2_1")
     assert 'op_name="jit(serve_composed_Q2_1)/filter/' in text
+    dims = {d: dict(eng.tables[d].columns) for d in attrs}
     idx = {d: eng.indexes[d] for d in dims}
     text = _compiled_text(runner._mega_program(name), fact, dims, idx,
                           params)

@@ -110,8 +110,7 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
 
 
 # -- serving programs: which ops the compiled HLO leaves unnamed ----------
-SF1_ROWS = {"lineorder": 6_000_000, "customer": 30_000, "supplier": 2_000,
-            "part": 200_000, "date": 2_556}
+SF1_FACT_ROWS = 6_000_000
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SCOPE = re.compile(r"(?<![\w])(probe|filter|group_key|aggregate)(?![\w])")
 
@@ -147,17 +146,12 @@ def _named_scopes(comps, comp, seen=None):
 
 @pytest.mark.parametrize("name", sorted(SSB_QUERIES))
 def test_serving_programs_unnamed_custom_fusions_are_aggregate(
-        name, one_chip, no_persistent_cache, serving_operands):
+        name, no_persistent_cache, serving_batch_hlo):
     """The benchmark's device-scope reader counts a serving program's
     custom fusion that carries no ``op_name`` as the group-by's
     scatter-add: at SF 1 shapes every such fusion holds ``aggregate`` ops
     only, and every named op at the entry has one of the four scopes."""
-    runner = BatchRunner()
-    fact, dims, probes = serving_operands(name)
-    params = jax.ShapeDtypeStruct((2, PARAM_QUERIES[name].n_params),
-                                  jnp.int32, sharding=one_chip)
-    text = runner._batch_program(name).lower(
-        fact, dims, probes, params).compile().as_text()
+    text = serving_batch_hlo(name)
     comps = _computations(text)
     entry = next(c for c in comps if text.find(f"ENTRY {c} ") >= 0)
     unnamed = 0
@@ -171,10 +165,27 @@ def test_serving_programs_unnamed_custom_fusions_are_aggregate(
     assert f"HloModule jit_serve_batch_{name.replace('.', '_')}" in text
 
 
+_GATHER = re.compile(r"= (\w+\[[\d,]*\])\{?[^ ]* gather\(")
+
+
+@pytest.mark.parametrize("name", sorted(SSB_QUERIES))
+def test_serving_programs_gather_nothing_fact_length(
+        name, no_persistent_cache, serving_batch_hlo):
+    """The serving batch programs read the cached fact-aligned dimension
+    columns: no gather in them, fused or not, yields a fact-length
+    result (a dimension predicate or group column gathered through the
+    probe's rows per query would)."""
+    shapes = _GATHER.findall(serving_batch_hlo(name))
+    n = str(SF1_FACT_ROWS)
+    assert not [s for s in shapes if n in s], shapes
+
+
 @pytest.fixture(scope="module")
 def serving_operands(one_chip):
     """A serving batch program's operands at SF 1 row counts, as shapes
-    on the described chip (column names from a tiny engine)."""
+    on the described chip (column names from a tiny engine): fact columns,
+    the fact-aligned dimension columns and the cached probes, each of
+    6,000,000 rows."""
     from repro.engine import SSBEngine, generate_ssb
 
     eng = SSBEngine(generate_ssb(sf=0.002, seed=3), mode="jspim")
@@ -184,11 +195,30 @@ def serving_operands(one_chip):
         return jax.ShapeDtypeStruct((n,), x.dtype, sharding=one_chip)
 
     def make(name):
-        fact, dims, probes = BatchRunner._operands(eng, name)
-        n = SF1_ROWS["lineorder"]
+        fact, attrs, probes = BatchRunner._operands(eng, name)
+        n = SF1_FACT_ROWS
         return ({k: sds(v, n) for k, v in fact.items()},
-                {d: {k: sds(v, SF1_ROWS[d]) for k, v in c.items()}
-                 for d, c in dims.items()},
+                {d: {k: sds(v, n) for k, v in c.items()}
+                 for d, c in attrs.items()},
                 {d: (sds(f, n), sds(r, n)) for d, (f, r) in probes.items()})
 
     return make
+
+
+@pytest.fixture(scope="module")
+def serving_batch_hlo(one_chip, serving_operands):
+    """The ``serve_batch_<Q>`` programs at width 2, compiled for a v5e
+    once per query and shared by the tests that read them."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            fact, attrs, probes = serving_operands(name)
+            params = jax.ShapeDtypeStruct(
+                (2, PARAM_QUERIES[name].n_params), jnp.int32,
+                sharding=one_chip)
+            texts[name] = BatchRunner()._batch_program(name).lower(
+                fact, attrs, probes, params).compile().as_text()
+        return texts[name]
+
+    return get
